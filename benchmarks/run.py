@@ -14,6 +14,8 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true",
                     help="skip CPU wall-clock measurements")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (fig8_dwc, pipeline_int8, roofline, serve_cnn,
                             serve_lm, table1_dse, table2_resources,

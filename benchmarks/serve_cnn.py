@@ -488,6 +488,8 @@ if __name__ == "__main__":
                     help="measured sub-10s trace subset; prints the "
                          "BENCH_serve.json schema to stdout")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.summary:
         print(summary_line())
     elif args.fast:

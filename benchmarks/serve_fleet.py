@@ -322,6 +322,8 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="{1,8} devices, 64 requests, no BENCH write")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.worker is not None:
         _worker_cnn(args.worker, args.requests, args.fast)
     elif args.lm_worker is not None:

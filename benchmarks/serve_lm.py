@@ -638,6 +638,8 @@ if __name__ == "__main__":
                          "shared-prompt trace: measured one-liners, lm_decode "
                          "fields merge-written to BENCH_serve.json")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.summary:
         print(summary_line())
     elif args.decode_summary:

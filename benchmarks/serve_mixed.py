@@ -236,6 +236,8 @@ if __name__ == "__main__":
     ap.add_argument("--fast", action="store_true",
                     help="smaller trace, same schema")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.summary:
         print(summary_line(fast=args.fast))
     else:

@@ -187,9 +187,9 @@ class EngineConfig:
     # w4/w8 programs -- and different group sizes -- never collide.
     w4_group_size: int = 64
     # Kernel backend: "ref" = pure-jnp oracle path (also the dry-run path:
-    # XLA-TPU fuses the same epilogues), "pallas" = Pallas TPU kernels.
+    # XLA-TPU fuses the same epilogues), "pallas" = Pallas TPU kernels
+    # (interpreted on the CPU backend only: kernels/ops.interpret_pallas).
     backend: str = "ref"
-    interpret: bool = True           # Pallas interpret mode (CPU container)
     # Paper features (each maps to a paper contribution; see DESIGN.md):
     use_dwc_engine: bool = True      # C4  DWC PE
     use_low_channel_unit: bool = True# C5  first-layer unit

@@ -86,9 +86,9 @@ def snap_group_size(k: int, group_size: int) -> int:
 
 
 def unpack_int4(packed: jax.Array) -> jax.Array:
-    """[K//2, N] packed bytes -> [K, N] int32 codes in [0, 15]."""
-    low = (packed & 0xF).astype(jnp.int32)
-    high = (packed >> 4).astype(jnp.int32)
+    """[K//2, N] packed bytes -> [K, N] int8 codes in [0, 15]."""
+    low = (packed & 0xF).astype(jnp.int8)
+    high = (packed >> 4).astype(jnp.int8)
     k2, n = packed.shape
     return jnp.stack([low, high], axis=1).reshape(2 * k2, n)
 

@@ -134,8 +134,6 @@ class RooflineReport:
 def _cost_get(cost, key: str) -> float:
     if cost is None:
         return 0.0
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     return float(cost.get(key, 0.0))
 
 

@@ -23,6 +23,8 @@ from __future__ import annotations
 from typing import Optional
 
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels.ref import act_fn
 
@@ -44,6 +46,31 @@ def _taps(x, k: int, stride: int):
                     kw:kw + (wo - 1) * stride + 1:stride, :]
 
 
+def scratch_taps(scr):
+    """In-kernel `_taps`: Mosaic slices loaded values at unit stride only,
+    so the pre-pool [H, W, C] map is staged in the f32 VMEM scratch `scr`
+    and each window is a strided load from it (pool inputs are f32 or
+    int32 codes, both exact in f32)."""
+    def taps(x, k: int, stride: int):
+        h, w = x.shape[-3], x.shape[-2]
+        ho = (h - k) // stride + 1
+        wo = (w - k) // stride + 1
+        scr[...] = x.astype(scr.dtype)
+        for kh in range(k):
+            for kw in range(k):
+                yield scr[pl.ds(kh, ho, stride=stride),
+                          pl.ds(kw, wo, stride=stride), :].astype(x.dtype)
+    return taps
+
+
+def tap_scratch(pool: str, h: int, w: int, c: int):
+    """Scratch shapes a kernel declares for `scratch_taps` (windowed pools
+    only; the global pool reduces in-register)."""
+    if pool in ("avg", "max"):
+        return [pltpu.VMEM((h, w, c), jnp.float32)]
+    return []
+
+
 def fused_chain(x: jnp.ndarray, *,
                 mid_scale: Optional[float] = None,
                 residual: Optional[jnp.ndarray] = None,
@@ -53,15 +80,18 @@ def fused_chain(x: jnp.ndarray, *,
                 pool: str = "none",
                 pool_kernel: int = 0,
                 pool_stride: int = 0,
-                out_scale: Optional[float] = None) -> jnp.ndarray:
+                out_scale: Optional[float] = None,
+                taps=None) -> jnp.ndarray:
     """Apply a fused Epilogue chain to the PE output.
 
     x: f32 [..., H, W, C], the conv/dwc result AFTER its own bias +
     activation, BEFORE any requant.  residual: raw operand values (int8 or
     f32), same shape.  Scales are compile-time python floats (static chain)
     or None (dynamic f32 chain).  Returns int8 for a static chain ending in
-    a requant (or the scale-preserving max tail), f32 otherwise.
+    a requant (or the scale-preserving max tail), f32 otherwise.  `taps`
+    walks the avg/max windows: `scratch_taps(ref)` inside a kernel.
     """
+    taps = taps or _taps
     static = mid_scale is not None
     if static:
         x = _qdq(x, mid_scale)                 # the absorbed conv edge
@@ -82,7 +112,7 @@ def fused_chain(x: jnp.ndarray, *,
         # Order-preserving on the quantized values: scale passes through,
         # exactly like the standalone max pool's scale-preserving rule.
         y = None
-        for t in _taps(x, pool_kernel, pool_stride):
+        for t in taps(x, pool_kernel, pool_stride):
             y = t if y is None else jnp.maximum(y, t)
         return y.astype(jnp.int8) if static else y
     if pool == "global":
@@ -97,12 +127,12 @@ def fused_chain(x: jnp.ndarray, *,
     else:                                       # avg
         if static:
             acc = None
-            for t in _taps(x.astype(jnp.int32), pool_kernel, pool_stride):
+            for t in taps(x.astype(jnp.int32), pool_kernel, pool_stride):
                 acc = t if acc is None else acc + t
             y = acc.astype(jnp.float32) * (cur / pool_kernel ** 2)
         else:
             acc = None
-            for t in _taps(x, pool_kernel, pool_stride):
+            for t in taps(x, pool_kernel, pool_stride):
                 acc = t if acc is None else acc + t
             y = acc / pool_kernel ** 2
     if static and out_scale is not None:

@@ -29,9 +29,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.ref import act_fn
+from repro.kernels.ref import act_fn, int4_group_combine
 from repro.kernels import _epilogue
-from repro.kernels._pallas_compat import compiler_params
+
+
+# v5e has 128 MiB of VMEM and Mosaic's default scoped limit is 16 MiB.  The
+# DSE sizes blocks against a 12 MiB model of the pipelined operand,
+# accumulator and output buffers (core/dse.py); a fused residual block, the
+# staged int32 dot result and the f32 epilogue temporaries of a 1024-row
+# block come on top (18.4 MiB for qwen2-1.5b's prefill down projection with
+# its residual), so the GEMM kernels run under a 48 MiB limit.
+_VMEM_LIMIT = 48 << 20
 
 
 def _kernel(a_ref, b_ref, a_scale_ref, w_scale_ref, bias_ref, os_ref, o_ref,
@@ -44,8 +52,7 @@ def _kernel(a_ref, b_ref, a_scale_ref, w_scale_ref, bias_ref, os_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # MAC chain link: one cascade step of the IC reduction.
-    acc_ref[...] += jnp.dot(a_ref[...].astype(jnp.int32),
-                            b_ref[...].astype(jnp.int32),
+    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
                             preferred_element_type=jnp.int32)
 
     # NL core: fused epilogue once the cascade completes.
@@ -79,8 +86,7 @@ def _kernel_res(a_ref, b_ref, a_scale_ref, w_scale_ref, bias_ref, os_ref,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[...].astype(jnp.int32),
-                            b_ref[...].astype(jnp.int32),
+    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
                             preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)
@@ -170,7 +176,8 @@ def matmul_int8_fused(a_q: jax.Array, b_q: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), odt),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],         # PsumStack
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -186,14 +193,45 @@ def matmul_int8_fused(a_q: jax.Array, b_q: jax.Array,
 # block keeps the group reduction inside one kernel instance.
 # ---------------------------------------------------------------------------
 
+def int4_groups(a_q: jax.Array, b_packed: jax.Array, g: int):
+    """Lay the int4 GEMM operands out by group, so every group is a
+    leading-dim index in VMEM: a_q [M, K] -> [G, 2, M, gs/2] (the even /
+    odd K columns, which meet the low / high nibbles), b_packed [K//2, N]
+    -> [G, gs/2, N].  Nibble pairs never straddle a group, so the split is
+    a reshape."""
+    m, k = a_q.shape
+    gs = k // g
+    a = a_q.reshape(m, g, gs // 2, 2).transpose(1, 3, 0, 2)
+    return a, b_packed.reshape(g, gs // 2, -1)
+
+
+def int4_group_mac(a_lo: jax.Array, a_hi: jax.Array, packed: jax.Array):
+    """The exact integer core, all groups at once: int32 partial sums
+    [G, M, N] of the activations (even / odd K columns [G, M, gs/2])
+    against the codes, and the activations' int32 row sums [G, M, 1] (what
+    each group's zero point multiplies).  Nibbles unpack to int8 and feed
+    the MXU's int8 path (Mosaic has no int32 matmul)."""
+    p = packed.astype(jnp.int32)
+    lo = (p & 0xF).astype(jnp.int8)
+    hi = (p >> 4).astype(jnp.int8)
+    dot = functools.partial(jnp.einsum, "gmk,gkn->gmn",
+                            preferred_element_type=jnp.int32)
+    part = dot(a_lo, lo) + dot(a_hi, hi)
+    asum = jnp.sum(a_lo.astype(jnp.int32) + a_hi.astype(jnp.int32),
+                   axis=-1, keepdims=True)
+    return part, asum
+
+
+def _w4_mac(a_ref, b_ref, wsc_ref, wz_ref):
+    """ref.int4_group_dot over this block's group-major VMEM refs."""
+    part, asum = int4_group_mac(a_ref[:, 0], a_ref[:, 1], b_ref[...])
+    return int4_group_combine(part, asum, wsc_ref[...], wz_ref[...])
+
+
 def _kernel_w4(a_ref, b_ref, wsc_ref, wz_ref, a_scale_ref, bias_ref, os_ref,
                o_ref, *, act: str, has_bias: bool,
                out_scale: Optional[float], vector_os: bool):
-    from repro.core.quant import unpack_int4
-    from repro.kernels.ref import int4_group_dot
-
-    codes = unpack_int4(b_ref[...])                     # [K, bn] in-register
-    x = int4_group_dot(a_ref[...], codes, wsc_ref[...], wz_ref[...])
+    x = _w4_mac(a_ref, b_ref, wsc_ref, wz_ref)
     x = x * a_scale_ref[...]
     if has_bias:
         x = x + bias_ref[...]
@@ -212,11 +250,7 @@ def _kernel_w4_res(a_ref, b_ref, wsc_ref, wz_ref, a_scale_ref, bias_ref,
                    add_act: str):
     """Residual-epilogue variant: the absorbed MISC add after an O/down
     projection rides the same in-register-dequant launch."""
-    from repro.core.quant import unpack_int4
-    from repro.kernels.ref import int4_group_dot
-
-    codes = unpack_int4(b_ref[...])
-    x = int4_group_dot(a_ref[...], codes, wsc_ref[...], wz_ref[...])
+    x = _w4_mac(a_ref, b_ref, wsc_ref, wz_ref)
     x = x * a_scale_ref[...]
     if has_bias:
         x = x + bias_ref[...]
@@ -266,17 +300,22 @@ def matmul_int4_fused(a_q: jax.Array, b_packed: jax.Array,
             else jnp.ones((1, n), jnp.float32))
     odt = jnp.int8 if out_scale is not None else out_dtype
 
+    # group-major operands: each group is a leading-dim index in VMEM
+    a_g, b_g = int4_groups(a_q, b_packed, g)
+    gs2 = kdim // g // 2
     grid = (m // bm, n // bn)
     in_specs = [
-        pl.BlockSpec((bm, kdim), lambda i, j: (i, 0)),        # A (whole K)
-        pl.BlockSpec((k2, bn), lambda i, j: (0, j)),          # B packed
-        pl.BlockSpec((g, bn), lambda i, j: (0, j)),           # group scales
-        pl.BlockSpec((g, bn), lambda i, j: (0, j)),           # group zeros
+        pl.BlockSpec((g, 2, bm, gs2), lambda i, j: (0, 0, i, 0)),  # A
+        pl.BlockSpec((g, gs2, bn), lambda i, j: (0, 0, j)),   # B packed
+        pl.BlockSpec((g, 1, bn), lambda i, j: (0, 0, j)),     # group scales
+        pl.BlockSpec((g, 1, bn), lambda i, j: (0, 0, j)),     # group zeros
         pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),           # a_scale
         pl.BlockSpec((1, bn), lambda i, j: (0, j)),           # bias
         pl.BlockSpec((1, bn), lambda i, j: (0, j)),           # out_scale
     ]
-    operands = [a_q, b_packed, w_scale, w_zero,
+    operands = [a_g, b_g,
+                w_scale.astype(jnp.float32).reshape(g, 1, n),
+                w_zero.astype(jnp.float32).reshape(g, 1, n),
                 a_scale.astype(jnp.float32).reshape(m, 1), bias2d, os2d]
     if residual is None:
         kernel = functools.partial(
@@ -296,7 +335,8 @@ def matmul_int4_fused(a_q: jax.Array, b_packed: jax.Array,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), odt),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT,
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(*operands)
@@ -313,20 +353,18 @@ def _kernel_pool(*refs, nk: int, act: str, has_bias: bool, has_res: bool,
                  mid_scale: Optional[float], res_scale: float, add_act: str,
                  add_scale: Optional[float], pool: str, pool_kernel: int,
                  pool_stride: int, out_scale: Optional[float]):
-    if has_res:
-        (a_ref, b_ref, asc_ref, wsc_ref, bias_ref, r_ref,
-         o_ref, acc_ref) = refs
-    else:
-        a_ref, b_ref, asc_ref, wsc_ref, bias_ref, o_ref, acc_ref = refs
-        r_ref = None
+    a_ref, b_ref, asc_ref, wsc_ref, bias_ref = refs[:5]
+    r_ref = refs[5] if has_res else None
+    o_ref, acc_ref = refs[5 + has_res:7 + has_res]
+    taps = (_epilogue.scratch_taps(refs[7 + has_res])
+            if len(refs) > 7 + has_res else None)
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[0].astype(jnp.int32),
-                            b_ref[...].astype(jnp.int32),
+    acc_ref[...] += jnp.dot(a_ref[0], b_ref[...],
                             preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)
@@ -343,7 +381,7 @@ def _kernel_pool(*refs, nk: int, act: str, has_bias: bool, has_res: bool,
             xs, mid_scale=mid_scale, residual=rs, res_scale=res_scale,
             add_act=add_act, add_scale=add_scale, pool=pool,
             pool_kernel=pool_kernel, pool_stride=pool_stride,
-            out_scale=out_scale)
+            out_scale=out_scale, taps=taps)
         y = y.reshape(-1, bn)
         y = jnp.pad(y, ((0, out_rows - y.shape[0]), (0, 0)))
         o_ref[0] = y.astype(o_ref.dtype)
@@ -415,8 +453,10 @@ def matmul_int8_pool(a_q: jax.Array, b_q: jax.Array, a_scale: jax.Array,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, out_rows, bn), lambda i, j, kk: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((g, out_rows, n), odt),
-        scratch_shapes=[pltpu.VMEM((rows_p, bn), jnp.int32)],
-        compiler_params=compiler_params(
+        scratch_shapes=([pltpu.VMEM((rows_p, bn), jnp.int32)]
+                        + _epilogue.tap_scratch(pool, ho, wo, bn)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -468,7 +508,8 @@ def matmul_f_fused(a: jax.Array, b: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, bias2d)

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._pallas_compat import compiler_params
 
 NEG_INF = -1e30
 
@@ -97,7 +96,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),        # running sum
             pltpu.VMEM((bq, d), jnp.float32),        # accumulator
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

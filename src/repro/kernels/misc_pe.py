@@ -14,9 +14,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels.dwc_pe import phase_windows
 from repro.kernels.ref import act_fn
-from repro.kernels._pallas_compat import compiler_params
 
 
 def _add_kernel(a_ref, b_ref, o_ref, *, sa: float, sb: float, act: str,
@@ -52,7 +53,7 @@ def misc_add(a: jax.Array, b: jax.Array, sa: float = 1.0, sb: float = 1.0,
                   pl.BlockSpec((block, lanes), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_p, lanes), odt),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(af, bf)
@@ -61,35 +62,38 @@ def misc_add(a: jax.Array, b: jax.Array, sa: float = 1.0, sb: float = 1.0,
 
 def _avgpool_kernel(x_ref, o_ref, *, window: int, stride: int,
                     ho: int, wo: int):
-    x = x_ref[0]
-    acc = jnp.zeros((ho, wo, x.shape[-1]), jnp.float32)
+    acc = jnp.zeros((ho, wo, x_ref.shape[-1]), jnp.float32)
+    phases = [x_ref[0, 0, p] for p in range(stride * stride)]
     for kh in range(window):
         for kw in range(window):
-            xs = jax.lax.slice(
-                x, (kh, kw, 0),
-                (kh + (ho - 1) * stride + 1, kw + (wo - 1) * stride + 1,
-                 x.shape[-1]),
-                (stride, stride, 1))
-            acc = acc + xs.astype(jnp.float32)
+            # stride phase (kh % s, kw % s) at offset (kh // s, kw // s)
+            x = phases[(kh % stride) * stride + kw % stride]
+            acc = acc + x[kh // stride:kh // stride + ho,
+                          kw // stride:kw // stride + wo, :].astype(
+                              jnp.float32)
     o_ref[0] = (acc / (window * window)).astype(o_ref.dtype)
 
 
 def avgpool2d(x: jax.Array, window: int, stride: int,
               out_dtype=jnp.float32, *, bc: int = 128,
               interpret: bool = False) -> jax.Array:
-    """[N, H, W, C] VALID average pool (C % bc == 0)."""
+    """[N, H, W, C] VALID average pool (C % bc == 0).  The wrapper splits
+    the map into its stride phases (dwc_pe.phase_windows), so the kernel
+    reads every window tap as a unit-stride slice."""
     n, h, w, c = x.shape
     assert c % bc == 0
     ho = (h - window) // stride + 1
     wo = (w - window) // stride + 1
+    xp = phase_windows(x, stride, stride, [0], -(-h // stride))
     return pl.pallas_call(
         functools.partial(_avgpool_kernel, window=window, stride=stride,
                           ho=ho, wo=wo),
         grid=(n, c // bc),
-        in_specs=[pl.BlockSpec((1, h, w, bc), lambda i, j: (i, 0, 0, j))],
+        in_specs=[pl.BlockSpec((1, 1) + xp.shape[2:5] + (bc,),
+                               lambda i, j: (i, 0, 0, 0, 0, j))],
         out_specs=pl.BlockSpec((1, ho, wo, bc), lambda i, j: (i, 0, 0, j)),
         out_shape=jax.ShapeDtypeStruct((n, ho, wo, c), out_dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(x)
+    )(xp)

@@ -1,9 +1,11 @@
 """Public kernel wrappers: backend selection, padding, DSE-chosen blocks.
 
 Every op has three backends:
-  * "ref"     -- pure-jnp oracle (kernels/ref.py).  CPU execution and the
-                 dry-run lowering use this path.
-  * "pallas"  -- the Pallas TPU kernel (interpret=True on this CPU container).
+  * "ref"     -- pure-jnp oracle (kernels/ref.py): the test oracle and the
+                 dry-run lowering path.
+  * "pallas"  -- the Pallas TPU kernel, compiled through Mosaic on a TPU and
+                 interpreted only where JAX's default backend is the CPU
+                 (`interpret_pallas`).
   * baseline  -- the XVDPU-analog unfused path (ref.matmul_int8_unfused).
 
 Wrappers own all shape legalization: flattening leading dims, padding M/N/K
@@ -27,6 +29,12 @@ from repro.kernels import _epilogue, conv_pe, dwc_pe, low_channel, misc_pe, ref
 # Quant modes with int8 activations on the Conv PE fabric (w4a8 packs LM
 # projection *weights* to int4; everything else runs exactly like w8a8).
 _INT8_ACTS = ("w8a8", "w4a8")
+
+
+def interpret_pallas() -> bool:
+    """The one place that decides Pallas interpret mode: kernels interpret
+    only on the CPU backend (tests); on a TPU they always compile."""
+    return jax.default_backend() == "cpu"
 
 
 def _round_up(x: int, m: int) -> int:
@@ -130,7 +138,7 @@ def linear_int8(x, w: QTensor, bias: Optional[jax.Array],
             aq, bq, asc, wsc, b, act, out_scale=osc, out_dtype=out_dtype,
             residual=r, res_scale=res_scale, mid_scale=mid_scale,
             add_act=add_act, bm=bm, bn=bn, bk=bk,
-            interpret=cfg.interpret)[:m, :n]
+            interpret=interpret_pallas())[:m, :n]
     else:
         assert residual is None, "fused residual composes in the wrapper"
         out = ref.matmul_int8_fused(xq.q, w.q, xq.scale, w_scale, bias, act,
@@ -174,6 +182,9 @@ def linear_w4(x, w: Q4Tensor, bias: Optional[jax.Array],
 
     if cfg.backend == "pallas" and not cfg.baseline:
         bm, bn, _ = pick_blocks(m, n, kdim, 1, cfg)
+        # the A block holds the whole K (double-buffered): keep it <= 4 MiB
+        while bm > 32 and 2 * bm * kdim > 4 << 20:
+            bm //= 2
         mp, np_ = _round_up(m, bm), _round_up(n, bn)
         aq = _pad2d(xq.q, mp, kdim)                        # pad M only
         asc = jnp.pad(xq.scale, ((0, mp - m), (0, 0)))
@@ -194,7 +205,7 @@ def linear_w4(x, w: Q4Tensor, bias: Optional[jax.Array],
             aq, bq, asc, wsc, wz, b, act, out_scale=osc, out_dtype=out_dtype,
             residual=r, res_scale=res_scale, mid_scale=mid_scale,
             add_act=add_act, bm=bm, bn=bn,
-            interpret=cfg.interpret)[:m, :n]
+            interpret=interpret_pallas())[:m, :n]
     else:
         assert residual is None, "fused residual composes in the wrapper"
         out = ref.matmul_int4_fused(xq.q, w.packed, xq.scale, w.scale, w.zero,
@@ -498,7 +509,7 @@ def _conv_pool_pallas(col_in, wt: QTensor, bias, act: str, ep, residual,
         add_scale=ep.add_scale if static and ep.add_scale else None,
         pool=ep.pool, pool_kernel=ep.pool_kernel, pool_stride=ep.pool_stride,
         out_scale=out_scale if static else None, out_dtype=out_dtype,
-        bn=bn, bk=bk, interpret=cfg.interpret)
+        bn=bn, bk=bk, interpret=interpret_pallas())
     pho, pwo = _epilogue.pooled_hw(ho, wo, ep.pool, ep.pool_kernel,
                                    ep.pool_stride)
     out = out[:, :pho * pwo, :oc]
@@ -638,7 +649,8 @@ def dwc2d(x, w, bias: Optional[jax.Array], stride: int,
                 add_act=ep.add_act,
                 add_scale=ep.add_scale if static and ep.add_scale else None,
                 pool=ep.pool, pool_kernel=ep.pool_kernel,
-                pool_stride=ep.pool_stride, bc=bc, interpret=cfg.interpret)
+                pool_stride=ep.pool_stride, bc=bc,
+                interpret=interpret_pallas())
         else:
             y = ref.dwc2d(xin, w_in, bias, stride, act,
                           a_scale=a_scale if quant else None,
@@ -658,7 +670,7 @@ def dwc2d(x, w, bias: Optional[jax.Array], stride: int,
                            a_scale=a_scale if quant else None,
                            w_scale=w_scale, out_scale=out_scale,
                            out_dtype=out_dtype,
-                           bc=bc, interpret=cfg.interpret)
+                           bc=bc, interpret=interpret_pallas())
     else:
         out = ref.dwc2d(xin, w_in, bias, stride, act,
                         a_scale=a_scale if quant else None,
@@ -678,7 +690,8 @@ def dwc1d_causal(x: jax.Array, w: jax.Array, bias: Optional[jax.Array],
         b_in = (jnp.pad(bias, (0, cp - c)) if (bias is not None and cp != c)
                 else bias)
         out = dwc_pe.dwc1d_causal(xin, w_in, b_in, act, out_dtype=x.dtype,
-                                  bc=min(128, cp), interpret=cfg.interpret)
+                                  bc=min(128, cp),
+                                  interpret=interpret_pallas())
         return out[..., :c]
     return ref.dwc1d_causal(x, w, bias, act, out_dtype=x.dtype)
 
@@ -745,7 +758,7 @@ def first_layer_conv(x, w, bias: Optional[jax.Array],
                 out_scale=out_scale if static else None, out_dtype=out_dtype,
                 mid_scale=ep.mid_scale if static and ep.mid_scale else None,
                 pool=ep.pool, pool_kernel=ep.pool_kernel,
-                pool_stride=ep.pool_stride, interpret=cfg.interpret)
+                pool_stride=ep.pool_stride, interpret=interpret_pallas())
         else:
             y = ref.low_channel_conv(xin, w_in, bias, stride, act,
                                      a_scale=a_scale, w_scale=w_scale,
@@ -758,7 +771,8 @@ def first_layer_conv(x, w, bias: Optional[jax.Array],
     if cfg.backend == "pallas":
         return low_channel.low_channel_conv(
             xin, w_in, bias, stride, act, a_scale=a_scale, w_scale=w_scale,
-            out_scale=out_scale, out_dtype=out_dtype, interpret=cfg.interpret)
+            out_scale=out_scale, out_dtype=out_dtype,
+            interpret=interpret_pallas())
     return ref.low_channel_conv(xin, w_in, bias, stride, act,
                                 a_scale=a_scale, w_scale=w_scale,
                                 out_scale=out_scale, out_dtype=out_dtype)
@@ -785,18 +799,23 @@ def misc_add(a: jax.Array, b: jax.Array, act: str, cfg: EngineConfig,
     if cfg.backend == "pallas":
         return misc_pe.misc_add(a, b, sa, sb, act, out_scale=out_scale,
                                 out_dtype=out_dtype,
-                                interpret=cfg.interpret)
+                                interpret=interpret_pallas())
     return ref.misc_add(a, b, sa, sb, act, out_scale=out_scale,
                         out_dtype=out_dtype)
 
 
 def avgpool2d(x: jax.Array, window: int, stride: int, cfg: EngineConfig,
               out_dtype=jnp.float32) -> jax.Array:
+    """Standalone average pool on the MISC core; channels pad to the
+    128-lane width (as for the DWC engine) and slice back."""
+    if not (cfg.misc_on_engine and cfg.backend == "pallas"):
+        return ref.avgpool2d(x, window, stride, out_dtype=out_dtype)
     c = x.shape[-1]
-    if cfg.misc_on_engine and cfg.backend == "pallas" and c % 128 == 0:
-        return misc_pe.avgpool2d(x, window, stride, out_dtype=out_dtype,
-                                 interpret=cfg.interpret)
-    return ref.avgpool2d(x, window, stride, out_dtype=out_dtype)
+    cp = _round_up(c, 128)
+    xin = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, cp - c))) if cp != c else x
+    out = misc_pe.avgpool2d(xin, window, stride, out_dtype=out_dtype,
+                            interpret=interpret_pallas())
+    return out[..., :c]
 
 
 # ---------------------------------------------------------------------------
@@ -809,7 +828,7 @@ def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """q: [B, H, L, D]; k, v: [B, H, S, D] (same head count: the caller
     repeats or groups GQA heads).  Pads L/S to block multiples."""
     from repro.kernels import flash_attn
-    cfg = cfg or EngineConfig(backend="pallas", interpret=True)
+    cfg = cfg or EngineConfig(backend="pallas")
     b, h, l, d = q.shape
     s = k.shape[2]
     bq = bkv = 128
@@ -821,7 +840,7 @@ def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
         # padded queries attend to nothing real; slice them off below
         out = flash_attn.flash_attention(
             qf, kf, vf, causal=causal, softcap=softcap,
-            scale=d ** -0.5, bq=bq, bkv=bkv, interpret=cfg.interpret)
+            scale=d ** -0.5, bq=bq, bkv=bkv, interpret=interpret_pallas())
     else:
         out = ref.attention(qf[:, None].transpose(1, 0, 2, 3), kf[:, None
                             ].transpose(1, 0, 2, 3), vf[:, None].transpose(
@@ -850,5 +869,5 @@ def paged_gather(pool: jax.Array, tables: jax.Array,
     if cfg.backend == "pallas" and not cfg.baseline:
         from repro.kernels import flash_attn
         return flash_attn.paged_gather(pool, tables,
-                                       interpret=cfg.interpret)
+                                       interpret=interpret_pallas())
     return ref.paged_gather(pool, tables)

@@ -83,28 +83,34 @@ def int4_group_dot(a_q: jax.Array, codes: jax.Array,
                    w_scale: jax.Array, w_zero: jax.Array) -> jax.Array:
     """The int4 weight-only MAC the Conv PE runs in-register.
 
-    a_q: int8/int32 [M, K]; codes: int32 [K, N] in [0, 15];
-    w_scale/w_zero: [G, N] per-group (K = G * gs).  Partial sums stay exact
-    int32 per group, then one f32 scale/zero combine per group:
-
-        out[m, n] = sum_g scale[g, n] * (a[m, g*gs:..] . codes[g*gs:.., n])
-                  + sum_g zero[g, n]  * (sum_k a[m, g*gs + k])
-
-    This is the single definition of the w4 GEMM value stream -- the Pallas
-    kernel applies the identical expression per block (column/row blocking
-    never reorders a group reduction), so ref and pallas agree bitwise.
+    a_q: int8 [M, K]; codes: int8 [K, N] in [0, 15]; w_scale/w_zero:
+    [G, N] per-group (K = G * gs).  Partial sums stay exact int32 per group
+    (one batched int8 dot), then int4_group_combine applies the per-group
+    f32 scale/zero.
     """
     m, k = a_q.shape
     g, n = w_scale.shape
     gs = k // g
-    ag = a_q.astype(jnp.int32).reshape(m, g, gs)
-    cg = codes.astype(jnp.int32).reshape(g, gs, n)
-    part = jnp.einsum("mgk,gkn->mgn", ag, cg,
+    ag = a_q.astype(jnp.int8).reshape(m, g, gs)
+    part = jnp.einsum("mgk,gkn->gmn", ag,
+                      codes.astype(jnp.int8).reshape(g, gs, n),
                       preferred_element_type=jnp.int32)
-    acc = jnp.sum(part.astype(jnp.float32)
-                  * w_scale.astype(jnp.float32)[None], axis=1)
-    asum = jnp.sum(ag, axis=-1).astype(jnp.float32)            # [M, G]
-    return acc + jnp.dot(asum, w_zero.astype(jnp.float32))
+    asum = jnp.sum(ag.astype(jnp.int32), axis=-1).T[..., None]   # [G, M, 1]
+    return int4_group_combine(part, asum, w_scale[:, None], w_zero[:, None])
+
+
+def int4_group_combine(part: jax.Array, asum: jax.Array,
+                       w_scale: jax.Array, w_zero: jax.Array) -> jax.Array:
+    """The f32 epilogue of the int4 MAC, one definition for ref and kernel:
+    int32 group partial sums part [G, M, N] and activation row sums asum
+    [G, M, 1] against the group scales / zeros [G, 1, N]:
+
+        out[m, n] = sum_g scale[g, n] * (a[m, g*gs:..] . codes[g*gs:.., n])
+                  + zero[g, n] * (sum_k a[m, g*gs + k])
+    """
+    return jnp.sum(part.astype(jnp.float32) * w_scale.astype(jnp.float32)
+                   + asum.astype(jnp.float32) * w_zero.astype(jnp.float32),
+                   axis=0)
 
 
 def matmul_int4_fused(a_q: jax.Array, b_packed: jax.Array,

@@ -20,15 +20,8 @@ from repro.models.params import resolve_pspec
 
 
 def make_mesh(shape, axes) -> Mesh:
-    """jax.make_mesh with an AxisType guard: older jax (< 0.5) has neither
-    `jax.sharding.AxisType` nor the `axis_types=` kwarg; newer jax defaults
-    new axes to Auto anyway, so passing it explicitly is only done when the
-    API exists."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -17,6 +17,7 @@ import numpy as np
 from repro import configs
 from repro.core import engine as eng_lib
 from repro.core.config import EngineConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import params as prm
 from repro.models import transformer as T
 from repro.models import whisper as W
@@ -40,7 +41,11 @@ def main(argv=None):
     arch = configs.get_arch(args.arch)
     if args.smoke:
         arch = configs.reduced(arch)
-    eng = EngineConfig(quant=args.quant, backend="ref",
+    enable_compile_cache()
+    # the Pallas engines on a TPU; elsewhere the jnp oracle path (Pallas
+    # would only run interpreted there)
+    backend = "pallas" if jax.default_backend() == "tpu" else "ref"
+    eng = EngineConfig(quant=args.quant, backend=backend,
                        kv_cache_dtype=args.kv,
                        baseline=args.baseline).resolved()
 
@@ -58,7 +63,8 @@ def main(argv=None):
     dt = time.perf_counter() - t0
     total = sum(len(o) for o in outs)
     print(f"served {len(outs)} requests, {total} tokens in {dt:.2f}s "
-          f"({total / dt:.1f} tok/s, quant={args.quant}, kv={args.kv})")
+          f"({total / dt:.1f} tok/s, quant={args.quant}, kv={args.kv}, "
+          f"backend={backend}, device={jax.devices()[0].device_kind})")
     for i, o in enumerate(outs[:4]):
         print(f"  req{i}: {o[:12].tolist()}...")
     return 0

@@ -64,6 +64,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro import compiler
 from repro.compiler.executor import Program
@@ -190,11 +191,22 @@ class CNNServeEngine(ProgramServeBase):
         eviction drops the trace alongside the program."""
         m = self._models[name]
         program = self.program_for(name)
-        run = self._jitted_for(
-            self._key(m), program,
-            lambda prog: jax.jit(
-                lambda p, im: compiler.execute(prog, p, im, self.eng)))
+        run = self._jitted_for(self._key(m), program, self._jit_program)
         return run, self._exec_params(m, program)
+
+    def _jit_program(self, prog: Program):
+        """jit of one program execution.  On a mesh every data replica runs
+        the whole program on its own rows (shard_map over the batch axis,
+        weights replicated): Mosaic kernels cannot be partitioned by XLA,
+        and no op of a CNN program mixes batch rows."""
+        def run(p, im):
+            return compiler.execute(prog, p, im, self.eng)
+        if self.mexec is None:
+            return jax.jit(run)
+        mesh = self.mexec.mesh
+        rows = self.mexec.batch_pspec(self.wave_rows)
+        return jax.jit(jax.shard_map(run, mesh=mesh, in_specs=(P(), rows),
+                                     out_specs=rows, check_vma=False))
 
     def _exec_params(self, m: _Model, program: Program):
         """The model's execution param tree with weight layouts folded at

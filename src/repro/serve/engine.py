@@ -316,6 +316,12 @@ class ServeEngine(ProgramServeBase):
         # bursts then run their projection GEMMs sharded, bit-identical
         # to single-device
         self.tp_placement = None
+        if self.mexec is not None and eng.backend == "pallas":
+            # XLA's partitioner cannot split a Mosaic kernel, and the TP
+            # plan shards the projection GEMMs' operands
+            raise ValueError(
+                "LM serving on a mesh (tensor-parallel decode) runs "
+                "backend='ref': XLA cannot partition compiled Pallas kernels")
         if self.mexec is not None:
             if self.is_audio:
                 self.params = self.mexec.replicate(self.params)

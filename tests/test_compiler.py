@@ -222,7 +222,7 @@ class TestStaticProgram:
         the ref backend (the engines' out_scale epilogues agree)."""
         cfg, params, x = _setup("mobilenetv2")
         engr = EngineConfig(quant="w8a8", backend="ref")
-        engp = EngineConfig(quant="w8a8", backend="pallas", interpret=True)
+        engp = EngineConfig(quant="w8a8", backend="pallas")
         qparams = eng_lib.quantize_params(params, engr)
         prog = compiler.compile_calibrated(cfg, params, [x])
         r = np.array(compiler.execute(prog, qparams, x, engr))
@@ -286,7 +286,7 @@ class TestWeightLayoutFolding:
         a = np.array(compiler.execute(prog, qparams, x, eng))
         b = np.array(compiler.execute(prog, folded, x, eng))
         np.testing.assert_array_equal(a, b)
-        engp = EngineConfig(quant="w8a8", backend="pallas", interpret=True)
+        engp = EngineConfig(quant="w8a8", backend="pallas")
         ap = np.array(compiler.execute(prog, qparams, x, engp))
         bp = np.array(compiler.execute(prog, folded, x, engp))
         np.testing.assert_array_equal(ap, bp)
@@ -389,7 +389,7 @@ class TestGoldenZooParity:
         eager dynamic w8a8 path within the golden max-logit-gap bound, and
         both correlate with the float reference."""
         cfg, params, x, prog, f = zoo_golden(name)
-        eng = EngineConfig(quant="w8a8", backend=backend, interpret=True)
+        eng = EngineConfig(quant="w8a8", backend=backend)
         qparams = eng_lib.quantize_params(params, eng)
         dyn = np.array(cnn.cnn_forward(qparams, x, cfg, eng))
         stat = np.array(compiler.execute(prog, qparams, x, eng))

@@ -266,7 +266,7 @@ class TestFusedExecutionParity:
         bit-identical to the unfused program (the in-register qdq points
         reproduce the unfused dataflow exactly)."""
         cfg, params, x, fused, unfused = fusion_golden(name)
-        eng = EngineConfig(quant="w8a8", backend=backend, interpret=True)
+        eng = EngineConfig(quant="w8a8", backend=backend)
         qparams = eng_lib.quantize_params(params, eng)
         a = np.array(compiler.execute(fused, qparams, x, eng))
         b = np.array(compiler.execute(unfused, qparams, x, eng))
@@ -279,7 +279,7 @@ class TestFusedExecutionParity:
         within golden tolerance of the unfused dynamic program."""
         cfg = dataclasses.replace(CNN_ZOO[name], input_hw=32)
         params, x = _setup(cfg)
-        eng = EngineConfig(quant="w8a8", backend="pallas", interpret=True)
+        eng = EngineConfig(quant="w8a8", backend="pallas")
         qparams = eng_lib.quantize_params(params, eng)
         a = np.array(compiler.execute(compiler.compile_cnn(cfg),
                                       qparams, x, eng))
@@ -341,7 +341,7 @@ class TestFusedExecutionParity:
         pu = _finish_program(unfused, None, scales, True)
         pf = _finish_program(fg, None, fscales, True)
         for backend in ("ref", "pallas"):
-            eng = EngineConfig(quant="w8a8", backend=backend, interpret=True)
+            eng = EngineConfig(quant="w8a8", backend=backend)
             qp = eng_lib.quantize_params(params, eng)
             a = np.array(compiler.execute(pf, qp, x, eng))
             b = np.array(compiler.execute(pu, qp, x, eng))

@@ -10,11 +10,12 @@ except ImportError:                       # offline container
 
 from repro.core.config import EngineConfig
 from repro.core.quant import QTensor, quantize
-from repro.kernels import conv_pe, dwc_pe, low_channel, misc_pe, ops, ref
+from repro.kernels import (_epilogue, conv_pe, dwc_pe, low_channel, misc_pe,
+                           ops, ref)
 
-PALLAS = EngineConfig(quant="w8a8", backend="pallas", interpret=True)
+PALLAS = EngineConfig(quant="w8a8", backend="pallas")
 REF = EngineConfig(quant="w8a8", backend="ref")
-FLOAT_PALLAS = EngineConfig(quant="none", backend="pallas", interpret=True)
+FLOAT_PALLAS = EngineConfig(quant="none", backend="pallas")
 
 
 def _rand_q(rng, shape):
@@ -207,6 +208,63 @@ class TestLowChannel:
                                     2, "SAME", "relu", eng_off)
         np.testing.assert_allclose(np.array(got), np.array(want),
                                    rtol=1e-3, atol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# Row tiling with a halo (the DWC engine and the Low-Channel unit)
+# ---------------------------------------------------------------------------
+
+class TestRowTiling:
+    """Shapes large enough that the kernels split their output rows over
+    several grid steps, each reading its own halo'd row window: every tile
+    edge must agree with the untiled ref oracle."""
+
+    @pytest.mark.parametrize("stride", [1, 2])
+    def test_dwc2d_tiles_with_residual(self, rng, stride):
+        k, c = 3, 128
+        hp = 97 if stride == 1 else 193         # 95 (prime) / 96 output rows
+        ho = (hp - k) // stride + 1
+        assert dwc_pe.row_tile(ho, ho) < ho     # several tiles
+        x = jnp.array(_rand_q(rng, (1, hp, hp, c)))
+        w = jnp.array(_rand_q(rng, (k, k, c)))
+        b = jnp.array(rng.normal(size=c).astype(np.float32))
+        wsc = jnp.array(rng.uniform(0.001, 0.01, c).astype(np.float32))
+        r = jnp.array(_rand_q(rng, (1, ho, ho, c)))
+        got = dwc_pe.dwc2d(x, w, b, stride, "relu6", a_scale=0.02,
+                           w_scale=wsc, residual=r, res_scale=0.05,
+                           add_act="relu", interpret=True)
+        y = ref.dwc2d(x, w, b, stride, "relu6", a_scale=jnp.float32(0.02),
+                      w_scale=wsc)
+        want = _epilogue.fused_chain(y, residual=r, res_scale=0.05,
+                                     add_act="relu")
+        np.testing.assert_allclose(np.array(got), np.array(want),
+                                   rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("pool", ["none", "max"])
+    def test_low_channel_stem_tiles(self, rng, pool):
+        """The ResNet stem at 112 px: 7x7/2 conv, 56x56 output, optionally
+        with the fused 3x3/2 max pool, whose tiles recompute the conv rows
+        their windows share across a tile edge."""
+        k, s, ic, oc = 7, 2, 3, 64
+        hp = 117
+        ho = (hp - k) // s + 1
+        pk, ps = (3, 2) if pool == "max" else (0, 0)
+        *_, nt = low_channel._tiles(ho, ho, pool, pk, ps)
+        assert nt > 1
+        x = jnp.array(_rand_q(rng, (1, hp, hp, ic)))
+        w = jnp.array(_rand_q(rng, (k, k, ic, oc)))
+        b = jnp.array(rng.normal(size=oc).astype(np.float32))
+        wsc = jnp.array(rng.uniform(0.001, 0.01, oc).astype(np.float32))
+        got = low_channel.low_channel_conv(
+            x, w, b, s, "relu", a_scale=0.02, w_scale=wsc, pool=pool,
+            pool_kernel=pk, pool_stride=ps, interpret=True)
+        y = ref.low_channel_conv(x, w, b, s, "relu",
+                                 a_scale=jnp.float32(0.02), w_scale=wsc)
+        want = _epilogue.fused_chain(y, pool=pool, pool_kernel=pk,
+                                     pool_stride=ps)
+        assert got.shape == want.shape
+        np.testing.assert_allclose(np.array(got), np.array(want),
+                                   rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -189,7 +189,7 @@ class TestGoldenPrefillParity:
         w8a8 forward within the golden bound and correlates with the float
         reference, on both kernel backends."""
         arch, params, toks, prog, f = lm_golden(name)
-        eng = EngineConfig(quant="w8a8", backend=backend, interpret=True)
+        eng = EngineConfig(quant="w8a8", backend=backend)
         qparams = eng_lib.quantize_params(params, eng)
         dyn = np.array(T.forward(qparams, {"tokens": toks}, arch, eng,
                                  compute_dtype=jnp.float32)[0])

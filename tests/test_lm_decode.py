@@ -187,7 +187,7 @@ class TestGoldenDecodeParity:
         token ids to the eager T.prefill/T.decode loop, on both kernel
         backends."""
         arch, params, toks = _setup(name)
-        eng = EngineConfig(quant="none", backend=backend, interpret=True)
+        eng = EngineConfig(quant="none", backend=backend)
         max_seq = L + STEPS + 2
         want = _greedy_ids(arch, params, np.asarray(toks), eng, STEPS,
                            max_seq=max_seq)

@@ -128,7 +128,7 @@ class TestPagedParity:
         ids bit-identical to the dense engine, on both kernel backends
         (gemma2 exercises the local ring layers that stay dense)."""
         arch, params = _setup(name)
-        eng = EngineConfig(quant="none", backend=backend, interpret=True)
+        eng = EngineConfig(quant="none", backend=backend)
         prompts = _prompts(arch, 5, seed=1)
         dense = _engine(arch, params, eng).generate(prompts,
                                                     max_new_tokens=3)

@@ -57,7 +57,7 @@ def _shared_prompts(arch, n, seed=0):
 
 
 def _engine(arch, params, backend="ref", sharing=True, **kw):
-    eng = EngineConfig(quant="none", backend=backend, interpret=True)
+    eng = EngineConfig(quant="none", backend=backend)
     kw.setdefault("batch_size", 2)
     kw.setdefault("max_seq", 32)
     kw.setdefault("kv_blocks", 16)

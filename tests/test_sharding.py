@@ -268,6 +268,24 @@ class TestWholeHeadTPRule:
             assert not mx.tp_shardable(name, arch, 1)
 
 
+class TestCompiledPallasOnMesh:
+    def test_lm_engine_refuses_compiled_pallas_on_a_mesh(self):
+        """XLA cannot partition a Mosaic kernel, so an LM engine on a mesh
+        with the Pallas backend is refused up front with the reason, on
+        every platform, before any weight is placed."""
+        from repro.core.config import EngineConfig
+        from repro.serve.engine import ServeEngine
+        from repro.serve.mesh_exec import make_serve_mesh
+
+        arch = configs.reduced(configs.get_arch("qwen2-1.5b"))
+        params = prm.init_params(T.lm_schema(arch), jax.random.PRNGKey(0))
+        with pytest.raises(ValueError, match="cannot partition"):
+            ServeEngine(arch, params,
+                        EngineConfig(quant="w8a8", backend="pallas"),
+                        batch_size=2, max_seq=32,
+                        mesh=make_serve_mesh(n_data=1))
+
+
 class TestLatencyTracker:
     def test_percentiles_over_completions(self):
         from repro.serve.base import LatencyTracker
