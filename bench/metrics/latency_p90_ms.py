@@ -1,0 +1,8 @@
+"""90th-percentile latency over all requests of the window (MLPerf
+SingleStream's metric), submit to logits on the host."""
+import numpy as np
+
+
+def read(r):
+    lat = r.window.latency_s
+    return float(np.percentile(lat, 90)) * 1e3 if lat else None
