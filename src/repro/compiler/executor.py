@@ -58,7 +58,8 @@ from repro.compiler.graph import (AddOp, AttnOp, ConcatOp, ConvOp, DwcOp,
                                   get_param, lower_transformer)
 from repro.compiler.passes import QuantPlan, fold_requant
 from repro.compiler.schedule import (MergedSchedule, Schedule,
-                                     level_schedule, merge_schedules)
+                                     engine_unit, level_schedule,
+                                     merge_schedules)
 from repro.core.config import ArchConfig, CNNConfig, EngineConfig
 from repro.core.quant import (Q4Tensor, QTensor, quantize_act_dynamic,
                               quantize_static)
@@ -490,6 +491,15 @@ def _dispatch_waves(program: Program) -> Iterable[Tuple[OpNode, ...]]:
             yield tuple(g.nodes[i] for i in level)
 
 
+def _eval_scoped(eval_node, n: OpNode, vals: Dict[int, object]):
+    """Evaluate one node under the named scope of its engine (conv_pe,
+    dwc_pe, low_channel, misc or mem): every HLO op it lowers to, glue
+    included, carries the engine in its op_name metadata.  Metadata only;
+    the computation is unchanged."""
+    with jax.named_scope(engine_unit(n)):
+        return eval_node(n, vals)
+
+
 def _run_scheduled(program: Program, eval_node, observer=None):
     """Evaluate the program wave-by-wave.  Each wave's ops read only values
     produced by earlier waves (`vals` is merged after the whole wave), so a
@@ -499,7 +509,7 @@ def _run_scheduled(program: Program, eval_node, observer=None):
     counts = _refcounts(g)
     vals: Dict[int, object] = {}
     for wave in _dispatch_waves(program):
-        produced = [(n, eval_node(n, vals)) for n in wave]
+        produced = [(n, _eval_scoped(eval_node, n, vals)) for n in wave]
         for n, v in produced:
             vals[n.id] = v
         for n, v in produced:
@@ -1054,7 +1064,7 @@ class _Lane:
         self.waves = tuple(_dispatch_waves(program))
 
     def step(self, k: int) -> None:
-        produced = [(n, self.eval_node(n, self.vals))
+        produced = [(n, _eval_scoped(self.eval_node, n, self.vals))
                     for n in self.waves[k]]
         for n, v in produced:
             self.vals[n.id] = v

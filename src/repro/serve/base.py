@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import hashlib
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from dataclasses import dataclass
 from typing import Callable, Dict, Hashable, List, Optional, Sequence, Tuple
 
@@ -37,6 +37,7 @@ from repro.compiler.schedule import merge_schedules
 from repro.core.config import EngineConfig
 from repro.core.program_cache import ProgramCache, ProgramKey
 from repro.serve.mesh_exec import MeshExecutor
+from repro.serve.tracing import DISABLED
 
 
 def calibration_digest(batches: Sequence, params=None,
@@ -271,11 +272,15 @@ class LatencyTracker:
     edge where its result becomes a host array, so the distribution
     measures what a caller actually waits -- queueing + batching + device
     time + the response-edge sync, not just kernel time.  percentiles()
-    feeds the `latency_ms` block of BENCH_serve.json."""
+    feeds the `latency_ms` block of BENCH_serve.json.  Only the newest
+    WINDOW samples are kept, so a long-running server's memory stays
+    bounded."""
+
+    WINDOW = 100_000
 
     def __init__(self):
         self._open: Dict[int, float] = {}
-        self.samples_ms: List[float] = []
+        self.samples_ms: deque = deque(maxlen=self.WINDOW)
 
     def submitted(self, ticket: int) -> None:
         self._open[ticket] = time.perf_counter()
@@ -313,6 +318,9 @@ class ProgramServeBase:
             mesh if isinstance(mesh, MeshExecutor) or mesh is None
             else MeshExecutor(mesh))
         self.latency = LatencyTracker()
+        # host spans of the serving path (serve/tracing.py); swap in an
+        # enabled SpanRecorder between pumps to record them
+        self.tracer = DISABLED
 
     # -- program cache -------------------------------------------------------
 

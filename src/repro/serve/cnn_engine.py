@@ -96,16 +96,13 @@ class WaveStats:
     padded: int = 0                   # empty slots across drained waves
     program_execs: int = 0            # program runs (>= waves: multi-model
                                       # waves run once per distinct model)
-    refilled_waves: int = 0           # waves topped up across pump epochs
 
     @property
     def occupancy(self) -> float:
+        """Fill-rate over physical buffers: the continuous-batching metric
+        the serving benchmark compares against the pad-and-mask baseline."""
         slots = self.requests + self.padded
         return self.requests / slots if slots else 0.0
-
-    # fill-rate over physical buffers: the continuous-batching metric the
-    # serving benchmark compares against the pad-and-mask baseline
-    fill_rate = occupancy
 
 
 class CNNServeEngine(ProgramServeBase):
@@ -280,7 +277,8 @@ class CNNServeEngine(ProgramServeBase):
         self._sched.next_epoch()
         results: Dict[int, np.ndarray] = {}
         for dev_logits, slots in in_flight:      # response edge: host sync
-            logits = np.asarray(dev_logits)
+            with self.tracer.span("wave.readback"):
+                logits = np.asarray(dev_logits)
             for slot, ticket in slots:
                 results[ticket] = logits[slot]   # mask foreign/pad slots
                 self.latency.completed(ticket)
@@ -290,18 +288,25 @@ class CNNServeEngine(ProgramServeBase):
         """Launch one wave buffer.  Slots may belong to different models
         (same shape): the buffer runs once per distinct model and each
         ticket reads its own slot's row.  Appends (device logits, slots)
-        per execution without blocking; _dispatch materializes."""
-        buf = np.zeros((self.wave_rows,) + shape, np.float32)
-        slots_of: Dict[str, List[Tuple[int, int]]] = {}
-        for slot, (ticket, (name, img)) in enumerate(wave):
-            buf[slot] = img
-            slots_of.setdefault(name, []).append((slot, ticket))
-        jbuf = jnp.asarray(buf)
-        if self.mexec is not None:
-            jbuf = self.mexec.place_wave(jbuf)   # rows shard over replicas
+        per execution without blocking; _dispatch materializes.  Records
+        the spans wave.fill, wave.to_device and, per execution,
+        wave.launch on self.tracer."""
+        span = self.tracer.span
+        with span("wave.fill"):
+            buf = np.zeros((self.wave_rows,) + shape, np.float32)
+            slots_of: Dict[str, List[Tuple[int, int]]] = {}
+            for slot, (ticket, (name, img)) in enumerate(wave):
+                buf[slot] = img
+                slots_of.setdefault(name, []).append((slot, ticket))
+        with span("wave.to_device"):
+            jbuf = jnp.asarray(buf)
+            if self.mexec is not None:
+                jbuf = self.mexec.place_wave(jbuf)   # rows shard over replicas
         for name, slots in slots_of.items():
-            run, qparams = self._executor_for(name)
-            in_flight.append((run(qparams, jbuf), slots))
+            with span("wave.launch"):
+                run, qparams = self._executor_for(name)
+                dev_logits = run(qparams, jbuf)
+            in_flight.append((dev_logits, slots))
             self.wave_stats.program_execs += 1
             self.execs_by_model[name] = self.execs_by_model.get(name, 0) + 1
         self.wave_stats.requests += len(wave)
@@ -331,12 +336,10 @@ class CNNServeEngine(ProgramServeBase):
                 fused[name] = {"launches": fs["launches"],
                                "fused_ops": fs["fused_ops"]}
         out["fused_programs"] = fused
-        self.wave_stats.refilled_waves = self._sched.stats.refilled_waves
         out.update({
             "waves": self.wave_stats.waves,
             "requests": self.wave_stats.requests,
             "padded_slots": self.wave_stats.padded,
-            "wave_occupancy": self.wave_stats.occupancy,
             "wave_fill_rate": self.wave_stats.occupancy,
             "program_execs": self.wave_stats.program_execs,
             "execs_by_model": dict(self.execs_by_model),

@@ -305,6 +305,17 @@ class TestLatencyTracker:
         lt.completed(99)
         assert lt.percentiles()["n"] == 0
 
+    def test_window_stays_bounded(self):
+        from repro.serve.base import LatencyTracker
+
+        lt = LatencyTracker()
+        n = lt.WINDOW + 10
+        for t in range(n):
+            lt.submitted(t)
+            lt.completed(t)
+        assert len(lt.samples_ms) == lt.WINDOW
+        assert lt.percentiles()["n"] == lt.WINDOW
+
 
 CNN_WAVE_PARITY = r"""
 import os
